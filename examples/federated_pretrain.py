@@ -10,7 +10,6 @@ resumable.  A few hundred steps ≈
     PYTHONPATH=src python examples/federated_pretrain.py --rounds 50   # full run
 """
 import argparse
-import sys
 
 from repro.launch import train as train_mod
 
@@ -20,8 +19,7 @@ def main() -> None:
     ap.add_argument("--rounds", type=int, default=3)
     ap.add_argument("--ckpt-dir", default="/tmp/fedhc_pretrain_ckpt")
     args = ap.parse_args()
-    sys.argv = [
-        "train",
+    train_mod.main([
         "--arch", "qwen-100m",  # d=512, 8L, vocab 151936 ≈ 103M params
         "--rounds", str(args.rounds),
         "--silos", "4",
@@ -29,8 +27,7 @@ def main() -> None:
         "--batch", "8",
         "--seq", "128",
         "--ckpt-dir", args.ckpt_dir,
-    ]
-    train_mod.main()
+    ])
 
 
 if __name__ == "__main__":

@@ -43,7 +43,6 @@ misses and fallbacks, mirrored onto the obs plane as the
 """
 from __future__ import annotations
 
-import inspect as _inspect
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -59,18 +58,6 @@ from repro.kernels.grouped_matmul.ops import grouped_matmul
 from repro.models.small import SmallModelConfig
 from repro.obs.metrics import Counter
 from repro.optim.optimizers import Optimizer, clip_by_global_norm
-
-try:  # jax>=0.4.35 exposes shard_map at top level
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
-
-# the replication-check kwarg was renamed check_rep -> check_vma across jax
-# releases; disable it under whichever name the installed jax understands
-_SHMAP_NOCHECK = {
-    ("check_vma" if "check_vma" in _inspect.signature(shard_map).parameters
-     else "check_rep"): False
-}
 
 PyTree = Any
 
@@ -116,7 +103,8 @@ class BatchedExecutor:
     ``mesh``/``rules`` opt the dense path into ``shard_map`` over the
     client axis; ``gmm_impl`` selects the grouped-matmul backend for the
     ragged path (``"ragged"`` = ``lax.ragged_dot``, ``"pallas"`` = the TPU
-    kernel, interpreted off-TPU, ``"dense"`` = masked dense matmul).
+    kernel, interpreted only on the CPU backend, ``"dense"`` = masked
+    dense matmul).
     The default is backend-aware: ``lax.ragged_dot`` lowers to a slow
     per-group loop on CPU where the masked-dense formulation is ~3x
     faster at FL-client sizes, so CPU defaults to ``"dense"`` and
@@ -291,10 +279,10 @@ class BatchedExecutor:
         wave = jax.vmap(one, in_axes=(None, 0, 0, 0))
         if entry is not None:
             cp = P(entry)
-            wave = shard_map(
+            wave = jax.shard_map(
                 wave, mesh=self.mesh,
                 in_specs=(P(), cp, cp, cp), out_specs=cp,
-                **_SHMAP_NOCHECK,
+                check_vma=False,
             )
         return jax.jit(wave)
 
@@ -409,6 +397,8 @@ class BatchedExecutor:
         """Unstack the wave's outputs into per-client results.  One bulk
         device→host transfer, then numpy views — per-client device slicing
         would cost hundreds of tiny dispatches and erase the wave's win."""
+        self.last_wave["platform"] = next(
+            iter(jax.tree.leaves(deltas)[0].devices())).platform
         deltas, metrics = jax.device_get((deltas, metrics))
         out = []
         for i, (c, bl) in enumerate(zip(clients, pulled)):

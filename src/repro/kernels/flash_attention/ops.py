@@ -12,6 +12,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
 
 
@@ -58,9 +59,9 @@ def flash_attention(
     *,
     causal: bool = True,
     window: Optional[int] = None,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """(B,S,H,D) flash attention.  Contiguous positions assumed (the model
     only routes full-sequence train/prefill here; decode and ring-buffer
     caches use the chunked JAX path)."""
-    return _fa_dif(q, k, v, causal, window, interpret)
+    return _fa_dif(q, k, v, causal, window, resolve_interpret(interpret))

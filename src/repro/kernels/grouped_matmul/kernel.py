@@ -1,12 +1,15 @@
 """Pallas TPU grouped-matmul (MoE expert GEMM), MegaBlocks adapted to TPU.
 
 GPU MegaBlocks exploits block-sparse CUDA GEMMs over an SM-scheduled grid.
-The TPU-native rethink: a *dense* (G, M/TM, N/TN) grid whose (g, mi) cells
+The TPU-native rethink: a *dense* (M/TM, N/TN, G) grid whose (mi, g) cells
 are masked out when the M-tile does not intersect group g's row range —
 the MXU always runs aligned (TM, K) × (K, TN) tiles resident in VMEM, and
 group boundaries are handled by row masks instead of irregular block
 pointers (TPU has no warp-level gather; contiguous VMEM tiles + masks keep
-the systolic array fed).
+the systolic array fed).  The group axis is innermost, so each output
+tile stays resident in VMEM while every group accumulates into it: a TPU
+pipeline writes an output block back when its index changes and never
+reads it again.
 
 Group offsets arrive via scalar prefetch (SMEM) so the index maps can skip
 whole tiles before their operands are even fetched.
@@ -20,13 +23,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import CompilerParams
-
 
 def _gmm_kernel(offs_ref, x_ref, w_ref, out_ref, *, tm: int):
-    """One (g, mi, ni) cell: accumulate group g's slice of M-tile mi."""
-    g = pl.program_id(0)
-    mi = pl.program_id(1)
+    """One (mi, ni, g) cell: accumulate group g's slice of M-tile mi."""
+    mi = pl.program_id(0)
+    g = pl.program_id(2)
 
     row0 = mi * tm
     start = offs_ref[g]
@@ -65,21 +66,21 @@ def gmm_pallas(
     offsets = jnp.concatenate(
         [jnp.zeros((1,), jnp.int32), jnp.cumsum(group_sizes).astype(jnp.int32)]
     )
-    grid = (g, m // tm, n // tn)
+    grid = (m // tm, n // tn, g)
     out = pl.pallas_call(
         functools.partial(_gmm_kernel, tm=tm),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((tm, k), lambda gi, mi, ni, offs: (mi, 0)),
-                pl.BlockSpec((1, k, tn), lambda gi, mi, ni, offs: (gi, 0, ni)),
+                pl.BlockSpec((tm, k), lambda mi, ni, gi, offs: (mi, 0)),
+                pl.BlockSpec((1, k, tn), lambda mi, ni, gi, offs: (gi, 0, ni)),
             ],
-            out_specs=pl.BlockSpec((tm, tn), lambda gi, mi, ni, offs: (mi, ni)),
+            out_specs=pl.BlockSpec((tm, tn), lambda mi, ni, gi, offs: (mi, ni)),
         ),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        compiler_params=CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(offsets, x, w)

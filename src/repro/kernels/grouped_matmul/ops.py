@@ -3,18 +3,21 @@
 impls:
   * "ragged": ``lax.ragged_dot`` — XLA-native, differentiable, the default
     for dry-run lowering and CPU execution.
-  * "pallas": the TPU kernel (interpret=True off-TPU); backward pass is
+  * "pallas": the TPU kernel (interpreted only on the CPU backend, see
+    ``repro.kernels.resolve_interpret``); backward pass is
     expressed with ``lax.ragged_dot`` transposes via custom_vjp.
   * "dense":  the one-hot oracle (tests/tiny shapes only).
 """
 from __future__ import annotations
 
 from functools import partial
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro.kernels import resolve_interpret
 from repro.kernels.grouped_matmul import ref as gmm_ref
 from repro.kernels.grouped_matmul.kernel import gmm_pallas
 
@@ -60,13 +63,14 @@ def grouped_matmul(
     w: jax.Array,
     group_sizes: jax.Array,
     impl: str = "ragged",
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """y[m] = x[m] @ w[g(m)] with rows pre-sorted by group."""
     if impl == "ragged":
         return lax.ragged_dot(x, w, group_sizes.astype(jnp.int32))
     if impl == "pallas":
-        return _gmm_pallas_dif(x, w, group_sizes.astype(jnp.int32), interpret)
+        return _gmm_pallas_dif(x, w, group_sizes.astype(jnp.int32),
+                               resolve_interpret(interpret))
     if impl == "dense":
         return gmm_ref.grouped_matmul_ref(x, w, group_sizes).astype(x.dtype)
     raise ValueError(f"unknown grouped_matmul impl: {impl}")

@@ -18,8 +18,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import CompilerParams
-
 
 def _rglru_kernel(la_ref, b_ref, y_ref, hout_ref, h_scr, *, q: int):
     c_idx = pl.program_id(2)
@@ -71,7 +69,7 @@ def rglru_pallas(
             jax.ShapeDtypeStruct((bs, w), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((1, tw), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

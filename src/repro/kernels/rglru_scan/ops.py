@@ -2,11 +2,12 @@
 from __future__ import annotations
 
 from functools import partial
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
 from repro.kernels.rglru_scan import ref as lru_ref
 from repro.kernels.rglru_scan.kernel import rglru_pallas
 
@@ -33,7 +34,7 @@ def rglru_scan(
     log_a: jax.Array,
     b: jax.Array,
     impl: str = "associative",
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """h_t = exp(log_a_t)·h_{t-1} + b_t over axis 1.  -> (y, h_final)."""
     if impl == "sequential":
@@ -41,7 +42,7 @@ def rglru_scan(
     if impl == "associative":
         return lru_ref.rglru_associative(log_a, b)
     if impl == "pallas":
-        return _rglru_pallas_dif(log_a, b, interpret)
+        return _rglru_pallas_dif(log_a, b, resolve_interpret(interpret))
     raise ValueError(f"unknown rglru impl: {impl}")
 
 

@@ -9,11 +9,12 @@ forward while autodiff stays exact.
 from __future__ import annotations
 
 from functools import partial
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
 from repro.kernels.ssd_scan import ref as ssd_ref
 from repro.kernels.ssd_scan.kernel import ssd_pallas
 
@@ -61,7 +62,7 @@ def ssd(
     *,
     chunk: int = 128,
     impl: str = "chunked",
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """SSD scan.  x (B,L,H,P), dt (B,L,H), a (H,), B/C (B,L,G,N).
 
@@ -72,7 +73,8 @@ def ssd(
     if impl == "chunked":
         return ssd_ref.ssd_chunked(x, dt, a, b_mat, c_mat, chunk=chunk)
     if impl == "pallas":
-        return _ssd_pallas_dif(x, dt, a, b_mat, c_mat, chunk, interpret)
+        return _ssd_pallas_dif(x, dt, a, b_mat, c_mat, chunk,
+                               resolve_interpret(interpret))
     raise ValueError(f"unknown ssd impl: {impl}")
 
 

@@ -1,7 +1,9 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any jax import: jax locks the device
-# count at first init, and the multi-pod dry-run needs 512 host devices.
+# The lines above MUST run before any jax import: jax fixes its platform and
+# device count at first init.  The dry-run lowers for 512 host devices and
+# never takes an accelerator, even on a machine that has one.
 
 # Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 #

@@ -9,9 +9,12 @@ import jax
 
 
 def make_production_mesh(*, multi_pod: bool = False):
+    """Auto axes: the model code places arrays with sharding constraints,
+    which ``jax.make_mesh``'s default Explicit axes refuse."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh():

@@ -16,6 +16,13 @@ Three roles, one protocol:
 * ``--role worker`` — run one client worker (``--client-id``) against a
   remote server at ``--host/--port``.
 
+One process per chip: in ``--role local`` the server process alone may
+hold an accelerator, and the workers it starts run with
+``JAX_PLATFORMS=cpu`` in their environment, set before they can start a
+JAX backend (a child that reached for the parent's chip would fail or
+hang on its lock).  In a real deployment each ``--role worker`` is its
+own machine and uses whatever device that machine has.
+
 Every process rebuilds the same deterministic world from the shared
 :class:`WorldSpec` (model config, budgets, Dirichlet data partition), so a
 worker owns exactly its data shard and nothing else travels out-of-band —
@@ -32,7 +39,9 @@ acceptance test in ``tests/test_net.py`` pins this).
 from __future__ import annotations
 
 import argparse
+import contextlib
 import dataclasses
+import os
 import time
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -42,6 +51,7 @@ from repro.core.runtime import FixedRuntime
 from repro.fed.server import (FLServer, LocalTransport, Message, MsgType,
                               RoundPolicy)
 from repro.fed.trainer import FedConfig, FederatedTrainer, build_fl_clients
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.small import SmallModelConfig
 from repro.optim.optimizers import make_optimizer
 
@@ -443,6 +453,28 @@ def _worker_entry(spec: WorldSpec, client_id: int, host: str, port: int) -> None
     run_worker(spec, client_id, host, port)
 
 
+#: environment of the worker processes ``run_multihost`` starts: they train
+#: on the host CPU, so the server process alone may hold an accelerator
+LOCAL_WORKER_ENV = {"JAX_PLATFORMS": "cpu"}
+
+
+@contextlib.contextmanager
+def _environ(updates: Dict[str, str]):
+    """Set ``updates`` in ``os.environ`` for the block, then restore.  A
+    process started inside the block inherits them from its first
+    instruction, before it can import JAX."""
+    saved = {k: os.environ.get(k) for k in updates}
+    os.environ.update(updates)
+    try:
+        yield
+    finally:
+        for k, v in saved.items():
+            if v is None:
+                os.environ.pop(k, None)
+            else:
+                os.environ[k] = v
+
+
 def run_aggregator(spec: WorldSpec, leaf_id: int, *,
                    host: Optional[str] = None, port: Optional[int] = None,
                    obs=None) -> None:
@@ -489,6 +521,9 @@ def run_multihost(spec: WorldSpec, *, transport=None,
                   skip_clients: Sequence[int] = ()) -> FederatedTrainer:
     """Loopback multi-host: N worker processes + the server in this one.
 
+    The workers start with ``LOCAL_WORKER_ENV`` (``JAX_PLATFORMS=cpu``):
+    on a machine with one chip, this process holds it.
+
     Pass a pre-built ``SocketServerTransport`` as ``transport`` and a
     ``connect`` (host, port) to interpose something between the workers
     and the server — the fault-injection tests and the chaos example dial
@@ -517,8 +552,9 @@ def run_multihost(spec: WorldSpec, *, transport=None,
                     daemon=True)
         for cid in range(spec.n_clients) if cid not in skip
     ]
-    for p in procs:
-        p.start()
+    with _environ(LOCAL_WORKER_ENV):
+        for p in procs:
+            p.start()
     try:
         trainer = run_server(spec, transport, round_timeout=round_timeout,
                              obs=obs, policy=policy)
@@ -593,6 +629,7 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
     if args.smoke:
         args.clients, args.rounds, args.participants = 4, 2, 4
     spec = _spec_from_args(args)
+    enable_compile_cache()
 
     obs = None
     if args.trace:

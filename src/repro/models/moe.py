@@ -31,28 +31,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.configs.base import ModelConfig
 from repro.kernels.grouped_matmul import ops as gmm_ops
 
-try:  # jax>=0.4.35 exposes shard_map at top level
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
-
-# the replication-check kwarg was renamed check_rep -> check_vma across jax
-# releases; disable it under whichever name the installed jax understands
-import inspect as _inspect
-
-_SHMAP_NOCHECK = {
-    ("check_vma" if "check_vma" in _inspect.signature(shard_map).parameters
-     else "check_rep"): False
-}
-
 Params = Dict[str, Any]
-
-
-def _axis_size(name: str):
-    """Mesh-axis size inside shard_map; lax.axis_size is newer-jax only."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(name)
-    return lax.psum(1, name)
 
 
 def init_moe(key: jax.Array, cfg: ModelConfig) -> Tuple[Params, Params]:
@@ -291,7 +270,7 @@ def _moe_shard_body_ep_resident(
         stride = 1
         for a in reversed(fsdp_axes):
             idx = idx + lax.axis_index(a) * stride
-            stride = stride * _axis_size(a)
+            stride = stride * lax.axis_size(a)
         out = lax.dynamic_slice_in_dim(out_full.reshape(-1, s, d), idx * b, b, axis=0)
     else:
         out = out_full.reshape(b, s, d)
@@ -327,11 +306,11 @@ def moe_ffn(
         w_spec = P(fsdp_axes if fsdp_axes else None, None, "model")
         wd_spec = P(fsdp_axes if fsdp_axes else None, "model", None)
         body = partial(_moe_shard_body, cfg=cfg, fsdp_axes=fsdp_axes, gmm_impl=gmm_impl)
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(None, None), w_spec, w_spec, wd_spec, P(b_axes, None, None)),
         out_specs=(P(b_axes, None, None), P()),
-        **_SHMAP_NOCHECK,
+        check_vma=False,
     )
     return fn(params["router"], params["wg"], params["wu"], params["wd"], x)
