@@ -13,6 +13,8 @@ from typing import Any, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.obs.trace import Tracer, span
+
 PyTree = Any
 
 
@@ -43,14 +45,21 @@ def fedavg(updates: Sequence[Tuple[PyTree, float]]) -> PyTree:
 
 
 def apply_deltas(global_params: PyTree, deltas: Sequence[Tuple[PyTree, float]],
-                 server_lr: float = 1.0) -> PyTree:
-    """FedAvg in delta form: θ ← θ + η·Σ wᵢ·Δᵢ / Σ wᵢ."""
-    avg_delta = fedavg(deltas)
-    return jax.tree.map(
-        lambda p, d: (p.astype(jnp.float32) + server_lr * d.astype(jnp.float32)).astype(p.dtype),
-        global_params,
-        avg_delta,
-    )
+                 server_lr: float = 1.0, *, tracer: Optional[Tracer] = None,
+                 pid: str = "trainer") -> PyTree:
+    """FedAvg in delta form: θ ← θ + η·Σ wᵢ·Δᵢ / Σ wᵢ.  The weighted sum
+    and the apply are the ``fold.sum`` and ``fold.apply`` spans (on
+    ``tracer``'s ``pid``/``rounds`` track when one is given)."""
+    nbytes = sum(a.nbytes for d, _ in deltas for a in jax.tree.leaves(d))
+    with span("fold.sum", tracer, pid, "rounds", deltas=len(deltas), bytes=nbytes):
+        avg_delta = fedavg(deltas)
+    with span("fold.apply", tracer, pid, "rounds"):
+        return jax.tree.map(
+            lambda p, d: (p.astype(jnp.float32)
+                          + server_lr * d.astype(jnp.float32)).astype(p.dtype),
+            global_params,
+            avg_delta,
+        )
 
 
 @dataclass

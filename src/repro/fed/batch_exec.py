@@ -57,6 +57,7 @@ from repro.fed.client import build_step_fn, make_small_step
 from repro.kernels.grouped_matmul.ops import grouped_matmul
 from repro.models.small import SmallModelConfig
 from repro.obs.metrics import Counter
+from repro.obs.trace import span
 from repro.optim.optimizers import Optimizer, clip_by_global_norm
 
 PyTree = Any
@@ -138,6 +139,10 @@ class BatchedExecutor:
         self._c_clients = reg.counter("client.batch_clients", tenant) if reg else Counter()
         self._c_compiles = reg.counter("client.batch_compiles", tenant) if reg else Counter()
         self._c_fallbacks = reg.counter("client.batch_fallbacks", tenant) if reg else Counter()
+        self._c_h2d = reg.counter("client.h2d_bytes", tenant) if reg else Counter()
+        self._c_d2h = reg.counter("client.d2h_bytes", tenant) if reg else Counter()
+        self._trace = obs.tracer if obs is not None and obs.tracer.enabled else None
+        self._tenant = tenant
 
     # ------------------------------------------------------------------
     # public API
@@ -160,23 +165,37 @@ class BatchedExecutor:
         self._c_waves.inc()
         self.stats.clients += len(clients)
         self._c_clients.inc(len(clients))
-        # pull every client's batches up front, in client order — consumes
-        # each ClientDataset's shuffle RNG exactly as the sequential loop
-        pulled = [list(c.data.batches(n_steps)) for c in clients]
-        mode = ("seq" if len(clients) == 1 or n_steps <= 0
-                else self._pick_mode(pulled))
-        self.last_wave = {"mode": mode, "clients": len(clients),
-                          "cache_hit": None}
+        with span("wave.prepare", self._trace, self._tenant, "train",
+                  clients=len(clients)) as sp:
+            # pull every client's batches up front, in client order —
+            # consumes each ClientDataset's shuffle RNG exactly as the
+            # sequential loop
+            pulled = [list(c.data.batches(n_steps)) for c in clients]
+            mode = ("seq" if len(clients) == 1 or n_steps <= 0
+                    else self._pick_mode(pulled))
+            sp.set(mode=mode)
+            self.last_wave = {"mode": mode, "clients": len(clients),
+                              "cache_hit": None}
+            if mode == "dense":
+                fn, host = self._prepare_dense(clients, pulled, round_idx)
+            elif mode == "ragged":
+                fn, host = self._prepare_ragged(clients, pulled, round_idx)
+        if mode == "seq":
+            self.stats.seq_clients += len(clients)
+            self._c_fallbacks.inc(len(clients))
+            return [self._run_sequential(global_params, c, bl)
+                    for c, bl in zip(clients, pulled)]
         if mode == "dense":
             self.stats.dense_clients += len(clients)
-            return self._run_dense(global_params, clients, pulled, round_idx)
-        if mode == "ragged":
+        else:
             self.stats.ragged_clients += len(clients)
-            return self._run_ragged(global_params, clients, pulled, round_idx)
-        self.stats.seq_clients += len(clients)
-        self._c_fallbacks.inc(len(clients))
-        return [self._run_sequential(global_params, c, bl)
-                for c, bl in zip(clients, pulled)]
+        # the compiled call moves the host arrays to the device
+        h2d = sum(a.nbytes for a in host)
+        self._c_h2d.inc(h2d)
+        with span("wave.launch", self._trace, self._tenant, "train",
+                  rows=sum(bl[0]["x"].shape[0] for bl in pulled), h2d_bytes=h2d):
+            deltas, metrics = fn(global_params, *host)
+        return self._split(deltas, metrics, clients, pulled)
 
     # ------------------------------------------------------------------
     # mode selection
@@ -286,7 +305,8 @@ class BatchedExecutor:
             )
         return jax.jit(wave)
 
-    def _run_dense(self, global_params, clients, pulled, round_idx):
+    def _prepare_dense(self, clients, pulled, round_idx):
+        """The dense wave's program and host arrays ``(xs, ys, keys)``."""
         xs = np.stack([np.stack([np.asarray(b["x"]) for b in bl])
                        for bl in pulled])                       # (C,S,B,...)
         ys = np.stack([np.stack([np.asarray(b["y"]) for b in bl])
@@ -301,9 +321,7 @@ class BatchedExecutor:
             keys = np.concatenate([keys, np.repeat(keys[-1:], pad, 0)])
         key = ("dense", C + pad, xs.shape[1:], str(xs.dtype),
                ys.shape[2:], str(ys.dtype), entry)
-        fn = self._get_fn(key, lambda: self._build_dense(entry))
-        deltas, metrics = fn(global_params, xs, ys, keys)
-        return self._split(deltas, metrics, clients, pulled)
+        return self._get_fn(key, lambda: self._build_dense(entry)), (xs, ys, keys)
 
     # ------------------------------------------------------------------
     # ragged path: clients are grouped_matmul groups
@@ -367,7 +385,9 @@ class BatchedExecutor:
 
         return jax.jit(wave)
 
-    def _run_ragged(self, global_params, clients, pulled, round_idx):
+    def _prepare_ragged(self, clients, pulled, round_idx):
+        """The ragged wave's program and host arrays
+        ``(xs, ys, gs, seg, keys)``."""
         C, S = len(clients), len(pulled[0])
         sizes = np.array([bl[0]["x"].shape[0] for bl in pulled], np.int64)
         width = int(np.prod(pulled[0][0]["x"].shape[1:]))  # same for all (checked)
@@ -382,14 +402,13 @@ class BatchedExecutor:
         ])                                                      # (S, M)
         # traced group metadata: the compiled program is reused across waves
         # with the same (C, S, M, D) envelope, whatever the row split
-        gs = jnp.asarray(sizes, jnp.int32)
-        seg = jnp.asarray(np.repeat(np.arange(C), sizes), jnp.int32)
+        gs = sizes.astype(np.int32)
+        seg = np.repeat(np.arange(C, dtype=np.int32), sizes)
         keys = _client_seed_keys(round_idx, [c.client_id for c in clients])
         key = ("ragged", self.gmm_impl, C, xs.shape[1:], str(xs.dtype),
                str(ys.dtype))
-        fn = self._get_fn(key, lambda: self._build_ragged(C))
-        deltas, metrics = fn(global_params, xs, ys, gs, seg, keys)
-        return self._split(deltas, metrics, clients, pulled)
+        return (self._get_fn(key, lambda: self._build_ragged(C)),
+                (xs, ys, gs, seg, keys))
 
     # ------------------------------------------------------------------
 
@@ -399,11 +418,17 @@ class BatchedExecutor:
         would cost hundreds of tiny dispatches and erase the wave's win."""
         self.last_wave["platform"] = next(
             iter(jax.tree.leaves(deltas)[0].devices())).platform
-        deltas, metrics = jax.device_get((deltas, metrics))
-        out = []
-        for i, (c, bl) in enumerate(zip(clients, pulled)):
-            delta = jax.tree.map(lambda a, _i=i: a[_i], deltas)
-            m = {k: float(v[i]) for k, v in metrics.items()}
-            n_seen = len(bl) * (bl[0]["x"].shape[0] if bl else 0)
-            out.append((delta, float(n_seen), m))
+        with span("wave.wait", self._trace, self._tenant, "train"):
+            # the device_get below waits for the same arrays anyway
+            jax.block_until_ready((deltas, metrics))
+        d2h = sum(a.nbytes for a in jax.tree.leaves((deltas, metrics)))
+        self._c_d2h.inc(d2h)
+        with span("wave.fetch", self._trace, self._tenant, "train", d2h_bytes=d2h):
+            deltas, metrics = jax.device_get((deltas, metrics))
+            out = []
+            for i, (c, bl) in enumerate(zip(clients, pulled)):
+                delta = jax.tree.map(lambda a, _i=i: a[_i], deltas)
+                m = {k: float(v[i]) for k, v in metrics.items()}
+                n_seen = len(bl) * (bl[0]["x"].shape[0] if bl else 0)
+                out.append((delta, float(n_seen), m))
         return out

@@ -41,7 +41,6 @@ path (clients that die are simply absent from aggregation).
 from __future__ import annotations
 
 import dataclasses
-import time
 from dataclasses import dataclass, field
 from enum import Enum
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
@@ -64,6 +63,7 @@ from repro.fed.compression import (
 )
 from repro.models.small import SmallModelConfig, init_small, small_loss
 from repro.obs.metrics import Counter
+from repro.obs.trace import span
 from repro.optim.optimizers import make_optimizer
 
 PyTree = Any
@@ -107,6 +107,10 @@ class RoundPhase(Enum):
     AGGREGATE = "aggregate"    # wall clock: FedAvg / async apply
     REPORT = "report"          # wall clock: eval, history, checkpoint
     DONE = "done"
+
+
+#: each phase step's span: ``fedhc.phase.<phase>`` in the profiler trace
+_PHASE_SPANS = {ph: f"phase.{ph.value}" for ph in RoundPhase}
 
 
 @dataclass
@@ -278,7 +282,9 @@ class FederatedTrainer:
         a driver calling ``step_round`` repeatedly makes incremental
         wall-clock progress it can interleave with other work."""
         if st.phase is not RoundPhase.DONE:
-            self._PHASE_STEPS[st.phase](self, st)
+            with span(_PHASE_SPANS[st.phase], self._trace, self.tenant, "rounds",
+                      round=self.round):
+                self._PHASE_STEPS[st.phase](self, st)
         return st.phase
 
     def _step_sample(self, st: RoundState) -> None:
@@ -399,11 +405,12 @@ class FederatedTrainer:
             st.result.spans.items(), key=lambda kv: kv[1].end
         )[:n_target]
         if self.dispatcher is not None:
-            t0 = time.time()
-            st.remote = self.dispatcher.train_round(
-                [cid for cid, _ in st.finishers], self.params,
-                fed.local_steps, self.round, compression=fed.compression,
-            )
+            with span("round.broadcast", self._trace, self.tenant, "rounds",
+                      round=self.round, clients=len(st.finishers)):
+                st.remote = self.dispatcher.train_round(
+                    [cid for cid, _ in st.finishers], self.params,
+                    fed.local_steps, self.round, compression=fed.compression,
+                )
             report = getattr(self.dispatcher, "last_round_report", None)
             if report is not None and report.get("mode") == "DEGRADED":
                 # quorum close: the dispatcher returned results for the
@@ -423,10 +430,6 @@ class FederatedTrainer:
                         args={"round": self.round,
                               "reported": len(st.finishers),
                               "stragglers": len(report.get("stragglers", ()))})
-            if self._trace is not None:
-                self._trace.wall_span(
-                    "round.broadcast", t0, time.time(), self.tenant, "rounds",
-                    args={"round": self.round, "clients": len(st.finishers)})
         st.phase = RoundPhase.COLLECT
 
     def _collect_client(self, st: RoundState, cid: int) -> None:
@@ -438,18 +441,13 @@ class FederatedTrainer:
         if st.remote is not None:
             delta, n_seen, m = st.remote[st.collect_idx]
         else:
-            client = st.by_id[cid]
-            t0 = time.time()
-            delta, n_seen, m = client.train_local(
-                self.params, self.step_fn, self.opt, n_steps=fed.local_steps
-            )
-            t1 = time.time()
+            with span("client.train", self._trace, self.tenant, "train",
+                      cid=cid, round=self.round) as sp:
+                delta, n_seen, m = st.by_id[cid].train_local(
+                    self.params, self.step_fn, self.opt, n_steps=fed.local_steps
+                )
             if self._h_train is not None:
-                self._h_train.observe(t1 - t0)
-            if self._trace is not None:
-                self._trace.wall_span(
-                    "client.train", t0, t1, self.tenant, "train",
-                    args={"cid": cid, "round": self.round})
+                self._h_train.observe(sp.seconds)
         self._ingest_delta(st, cid, delta, n_seen, m)
 
     def _ingest_delta(self, st: RoundState, cid: int, delta, n_seen, m) -> None:
@@ -479,20 +477,14 @@ class FederatedTrainer:
         (``BatchedExecutor.run_wave``), then ingest the per-client results
         in the same order — aggregation order and compression seeds are
         identical to collecting the clients one at a time."""
-        t0 = time.time()
-        results = self.batch_exec.run_wave(
-            self.params, [st.by_id[c] for c in cids],
-            self.fed.local_steps, self.round,
-        )
-        t1 = time.time()
-        if self._h_train is not None:
-            self._h_train.observe((t1 - t0) / max(len(cids), 1))
-        if self._trace is not None:
+        with span("client.batch_wave", self._trace, self.tenant, "train",
+                  round=self.round, clients=len(cids)) as sp:
+            results = self.batch_exec.run_wave(
+                self.params, [st.by_id[c] for c in cids],
+                self.fed.local_steps, self.round,
+            )
             lw = self.batch_exec.last_wave
-            self._trace.wall_span(
-                "client.batch_wave", t0, t1, self.tenant, "train",
-                args={"round": self.round, "clients": len(cids),
-                      "mode": lw.get("mode"), "cache_hit": lw.get("cache_hit")})
+            sp.set(mode=lw.get("mode"), cache_hit=lw.get("cache_hit"))
         for cid, (delta, n_seen, m) in zip(cids, results):
             self._ingest_delta(st, cid, delta, n_seen, m)
 
@@ -511,17 +503,15 @@ class FederatedTrainer:
     def _step_aggregate(self, st: RoundState) -> None:
         fed = self.fed
         if st.deltas:
-            t0 = time.time()
-            if fed.aggregation == "async":
-                for (delta, w), (cid, span) in zip(st.deltas, st.finishers):
-                    if self.async_agg.add(delta, w, self.round):
-                        self.params = self.async_agg.flush(self.params)
-            else:
-                self.params = apply_deltas(self.params, st.deltas, fed.server_lr)
-            if self._trace is not None:
-                self._trace.wall_span(
-                    "round.aggregate", t0, time.time(), self.tenant, "rounds",
-                    args={"round": self.round, "deltas": len(st.deltas)})
+            with span("round.aggregate", self._trace, self.tenant, "rounds",
+                      round=self.round, deltas=len(st.deltas)):
+                if fed.aggregation == "async":
+                    for (delta, w), _ in zip(st.deltas, st.finishers):
+                        if self.async_agg.add(delta, w, self.round):
+                            self.params = self.async_agg.flush(self.params)
+                else:
+                    self.params = apply_deltas(self.params, st.deltas, fed.server_lr,
+                                               tracer=self._trace, pid=self.tenant)
         st.phase = RoundPhase.REPORT
 
     def _step_report(self, st: RoundState) -> None:

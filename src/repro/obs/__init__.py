@@ -3,7 +3,9 @@
 One bundle (``ObsPlane``) threads through every layer of the stack:
 
 * ``obs.tracer`` — span/instant events against both the simulated fabric
-  clock and the wall clock (``repro.obs.trace``);
+  clock and the wall clock (``repro.obs.trace``); work spans go through
+  ``span``, which also puts them in the JAX profiler trace as
+  ``fedhc.<name>``;
 * ``obs.registry`` — counters / gauges / bounded histograms with a
   normative name table (``repro.obs.metrics.CANONICAL_METRICS``);
 * export — Chrome trace-event / Perfetto JSON (``repro.obs.export``,
@@ -20,11 +22,11 @@ from typing import Optional
 
 from .metrics import (CANONICAL_METRICS, Counter, Gauge, Histogram,
                       MetricsRegistry)
-from .trace import NULL_TRACER, NullTracer, Tracer
+from .trace import NULL_TRACER, NullTracer, Tracer, span
 
 __all__ = [
     "CANONICAL_METRICS", "Counter", "Gauge", "Histogram", "MetricsRegistry",
-    "NULL_TRACER", "NullTracer", "Tracer", "ObsPlane",
+    "NULL_TRACER", "NullTracer", "Tracer", "ObsPlane", "span",
 ]
 
 

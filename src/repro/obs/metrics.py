@@ -180,12 +180,14 @@ CANONICAL_METRICS: Dict[str, str] = {
     "fault.wal_appends": "counter — records appended to the round journal",
     "fault.wal_replays": "counter — uploads restored from the journal on restart",
     # worker-side, piggybacked via the STATS blob
-    "client.train_seconds": "histogram — wall-clock local training time (s)",
+    "client.train_seconds": "histogram — wall-clock local training time of one client (s)",
     # batched client execution (repro.fed.batch_exec)
     "client.batch_waves": "counter — batched COLLECT waves executed",
     "client.batch_clients": "counter — clients trained through batched waves",
     "client.batch_compiles": "counter — wave programs built (compile-cache misses)",
     "client.batch_fallbacks": "counter — wave clients run on the sequential fallback",
+    "client.h2d_bytes": "counter — host-to-device bytes of batched waves' inputs",
+    "client.d2h_bytes": "counter — device-to-host bytes of batched waves' deltas and metrics",
     # roofline accounting (per-device HLO collectives)
     "roofline.wire_bytes": "counter — per-device collective wire bytes (float)",
     # hierarchical aggregation tree (repro.fed.hier)

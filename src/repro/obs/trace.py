@@ -19,9 +19,14 @@ either a ``Tracer`` or ``None`` and guard with ``if self._trace is not
 None`` — with tracing disabled the per-event cost is one attribute load
 and a branch, nothing else.  ``NULL_TRACER`` exists for call sites that
 prefer unconditional calls; every method is a no-op.
+
+Wall-clock work spans go through :class:`span`, which puts each one in the
+JAX profiler trace as ``fedhc.<name>`` (on the clock the device's
+operations share) and, given a ``Tracer``, records it here as well.
 """
 from __future__ import annotations
 
+import functools
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -189,3 +194,62 @@ class NullTracer(Tracer):
 
 
 NULL_TRACER = NullTracer()
+
+#: profiler names are ``PROFILER_PREFIX + name``: the prefix keeps the
+#: program's spans apart from a caller's own annotations
+PROFILER_PREFIX = "fedhc."
+
+
+@functools.lru_cache(maxsize=None)
+def _annotation():
+    """``jax.profiler.TraceAnnotation``, or None without JAX; looked up on
+    first use, so importing ``repro.obs`` never imports JAX."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
+
+
+class span:
+    """Context manager: one wall-clock span of work.
+
+    Always opens ``jax.profiler.TraceAnnotation("fedhc." + name, **args)``
+    (free when no profiler trace is being taken); with a ``tracer`` it also
+    records ``name`` as a :meth:`Tracer.wall_span` on ``pid``/``tid`` with
+    the same args.  :meth:`set` adds args learnt inside the span;
+    ``seconds`` holds the span's duration once it has closed.
+    """
+
+    __slots__ = ("name", "tracer", "pid", "tid", "args", "t0", "seconds", "_ann")
+
+    def __init__(self, name: str, tracer: Optional[Tracer] = None, pid: str = "",
+                 tid: str = "", **args):
+        self.name = name
+        self.tracer = tracer
+        self.pid = pid
+        self.tid = tid
+        self.args = args
+        self.seconds = 0.0
+
+    def __enter__(self) -> "span":
+        ann = _annotation()
+        self._ann = None if ann is None else ann(PROFILER_PREFIX + self.name, **self.args)
+        if self._ann is not None:
+            self._ann.__enter__()
+        self.t0 = time.time()
+        return self
+
+    def set(self, **args) -> None:
+        self.args.update(args)
+        if self._ann is not None:
+            self._ann.set_metadata(**args)
+
+    def __exit__(self, *exc) -> None:
+        t1 = time.time()
+        self.seconds = t1 - self.t0
+        if self.tracer is not None and exc[0] is None:
+            self.tracer.wall_span(self.name, self.t0, t1, self.pid, self.tid,
+                                  args=self.args or None)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
