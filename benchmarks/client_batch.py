@@ -116,6 +116,7 @@ def bench_cell(name: str, n_clients: int, batch_sizes, steps: int,
         cl, params = build_world(n_clients, batch_sizes, seed=1 + rep)
         t0 = time.perf_counter()
         bat_res = ex.run_wave(params, cl, steps, round_idx=1 + rep)
+        jax.block_until_ready([d for d, _, _ in bat_res])
         best_bat = min(best_bat, time.perf_counter() - t0)
 
     stats = ex.stats.as_dict()

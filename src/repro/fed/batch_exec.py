@@ -36,6 +36,11 @@ so cross-path comparisons are allclose (documented in
 docs/architecture.md § batched executor), while the single-client
 fallback stays bit-identical.
 
+The wave hands its deltas over on the device: one compiled unstack turns
+the stacked outputs into one ``jax.Array`` tree per client, and only the
+per-client metrics cross to the host.  The FedAvg fold
+(``repro.core.aggregation.fedavg``) then sums them where they are.
+
 Compiled wave programs are cached on the wave *envelope* (mode, client
 count, steps, batch geometry, dtypes); :class:`WaveStats` counts hits,
 misses and fallbacks, mirrored onto the obs plane as the
@@ -158,7 +163,8 @@ class BatchedExecutor:
         """Train every client in ``clients`` for ``n_steps`` local steps
         from ``global_params``; returns ``(delta, n_seen, metrics)`` per
         client, in client order — the exact contract of
-        ``FLClient.train_local`` looped sequentially."""
+        ``FLClient.train_local`` looped sequentially.  The deltas are
+        device arrays."""
         if not clients:
             return []
         self.stats.waves += 1
@@ -413,22 +419,33 @@ class BatchedExecutor:
     # ------------------------------------------------------------------
 
     def _split(self, deltas, metrics, clients, pulled):
-        """Unstack the wave's outputs into per-client results.  One bulk
-        device→host transfer, then numpy views — per-client device slicing
-        would cost hundreds of tiny dispatches and erase the wave's win."""
+        """Unstack the wave's outputs into per-client results.  The deltas
+        stay on the device: one dispatch of :func:`_unstack` makes each
+        client's delta its own ``jax.Array`` (mesh-pad filler rows are
+        dropped), and only the metrics come to the host."""
         self.last_wave["platform"] = next(
             iter(jax.tree.leaves(deltas)[0].devices())).platform
         with span("wave.wait", self._trace, self._tenant, "train"):
-            # the device_get below waits for the same arrays anyway
+            # what the metrics' fetch below waits for anyway
             jax.block_until_ready((deltas, metrics))
-        d2h = sum(a.nbytes for a in jax.tree.leaves((deltas, metrics)))
+        d2h = sum(a.nbytes for a in jax.tree.leaves(metrics))
         self._c_d2h.inc(d2h)
         with span("wave.fetch", self._trace, self._tenant, "train", d2h_bytes=d2h):
-            deltas, metrics = jax.device_get((deltas, metrics))
+            per_client = _unstack(deltas)[:len(clients)]
+            metrics = jax.device_get(metrics)
             out = []
-            for i, (c, bl) in enumerate(zip(clients, pulled)):
-                delta = jax.tree.map(lambda a, _i=i: a[_i], deltas)
+            for i, (delta, bl) in enumerate(zip(per_client, pulled)):
                 m = {k: float(v[i]) for k, v in metrics.items()}
                 n_seen = len(bl) * (bl[0]["x"].shape[0] if bl else 0)
                 out.append((delta, float(n_seen), m))
         return out
+
+
+@jax.jit
+def _unstack(stacked: PyTree) -> List[PyTree]:
+    """Every row of a wave's stacked per-client tree as a tree of its own,
+    in one program (jit keys it on the stacked shapes, so there is one for
+    each wave program); eager per-client, per-leaf slicing would cost a
+    dispatch each."""
+    rows = jax.tree.leaves(stacked)[0].shape[0]
+    return [jax.tree.map(lambda a, _i=i: a[_i], stacked) for i in range(rows)]

@@ -187,6 +187,9 @@ class FederatedTrainer:
         self._h_train = (obs.registry.histogram("client.train_seconds",
                                                 self.tenant)
                          if obs is not None else None)
+        # host-resident deltas the fold moved to the device
+        self._fold_h2d = (obs.registry.counter("fold.h2d_bytes", self.tenant)
+                          if obs is not None else Counter())
         self._m_degraded = (obs.registry.counter("round.degraded",
                                                  self.tenant)
                             if obs is not None else Counter())
@@ -467,7 +470,8 @@ class FederatedTrainer:
             self._comm.inc(tree_wire_bytes(delta))
             delta = decompress_tree(delta)
         else:
-            self._comm.inc(sum(np.asarray(l).nbytes for l in jax.tree.leaves(delta)))
+            # leaf nbytes need no pull: a device delta stays on the device
+            self._comm.inc(sum(l.nbytes for l in jax.tree.leaves(delta)))
         st.deltas.append((delta, float(n_seen)))
         st.train_metrics = m
         st.collect_idx += 1
@@ -476,7 +480,8 @@ class FederatedTrainer:
         """Train a whole wave of finishers as ONE compiled program
         (``BatchedExecutor.run_wave``), then ingest the per-client results
         in the same order — aggregation order and compression seeds are
-        identical to collecting the clients one at a time."""
+        identical to collecting the clients one at a time.  The deltas
+        arrive on the device, as the sequential path's do."""
         with span("client.batch_wave", self._trace, self.tenant, "train",
                   round=self.round, clients=len(cids)) as sp:
             results = self.batch_exec.run_wave(
@@ -508,10 +513,12 @@ class FederatedTrainer:
                 if fed.aggregation == "async":
                     for (delta, w), _ in zip(st.deltas, st.finishers):
                         if self.async_agg.add(delta, w, self.round):
-                            self.params = self.async_agg.flush(self.params)
+                            self.params = self.async_agg.flush(self.params,
+                                                               h2d=self._fold_h2d)
                 else:
                     self.params = apply_deltas(self.params, st.deltas, fed.server_lr,
-                                               tracer=self._trace, pid=self.tenant)
+                                               tracer=self._trace, pid=self.tenant,
+                                               h2d=self._fold_h2d)
         st.phase = RoundPhase.REPORT
 
     def _step_report(self, st: RoundState) -> None:

@@ -187,7 +187,9 @@ CANONICAL_METRICS: Dict[str, str] = {
     "client.batch_compiles": "counter — wave programs built (compile-cache misses)",
     "client.batch_fallbacks": "counter — wave clients run on the sequential fallback",
     "client.h2d_bytes": "counter — host-to-device bytes of batched waves' inputs",
-    "client.d2h_bytes": "counter — device-to-host bytes of batched waves' deltas and metrics",
+    "client.d2h_bytes": "counter — device-to-host bytes of batched waves' metrics (the deltas stay on the device)",
+    # aggregation (repro.core.aggregation)
+    "fold.h2d_bytes": "counter — host-resident delta bytes the FedAvg fold moved to the device",
     # roofline accounting (per-device HLO collectives)
     "roofline.wire_bytes": "counter — per-device collective wire bytes (float)",
     # hierarchical aggregation tree (repro.fed.hier)

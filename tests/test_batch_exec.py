@@ -145,6 +145,66 @@ def test_ragged_zero_example_client_gets_exact_zero_delta():
     assert _max_delta_diff([batched[0], batched[2]], seq) < 1e-5
 
 
+# ------------------- the device-resident hand-off ---------------------------
+
+
+@pytest.mark.parametrize("sizes,mode", [([4] * 5, "dense"), ([4, 0, 6, 2], "ragged")])
+def test_wave_deltas_stay_on_device_equal_to_the_stacked_rows(sizes, mode):
+    """A batched wave hands each client's delta over as device arrays,
+    equal bit for bit to that client's row of the wave program's stacked
+    output; a zero-row ragged client's delta is exactly zero."""
+    ex = BatchedExecutor(MCFG, OPT)
+    cl, params = _world(sizes, seed=13)
+    res = ex.run_wave(params, cl, 2, round_idx=4)
+    assert ex.last_wave["mode"] == mode and len(res) == len(sizes)
+    cl, params = _world(sizes, seed=13)
+    pulled = [list(c.data.batches(2)) for c in cl]
+    prepare = ex._prepare_dense if mode == "dense" else ex._prepare_ragged
+    fn, host = prepare(cl, pulled, 4)
+    stacked, _ = jax.device_get(fn(params, *host))
+    for i, (delta, n_seen, _) in enumerate(res):
+        for a, b in zip(jax.tree.leaves(delta), jax.tree.leaves(stacked)):
+            assert isinstance(a, jax.Array)
+            assert np.array_equal(np.asarray(a), b[i])
+        if sizes[i] == 0:
+            assert n_seen == 0.0
+            assert all(not np.any(np.asarray(a)) for a in jax.tree.leaves(delta))
+
+
+def test_unstack_keeps_only_the_wave_clients_rows():
+    """Mesh-pad filler (rows past the wave's clients) never reaches the
+    per-client results, and the unstack program is the stacked shape's,
+    not the client count's."""
+    from repro.fed.batch_exec import _unstack
+
+    ex = BatchedExecutor(MCFG, OPT)
+    stacked = {"w": jax.numpy.arange(8 * 3.0).reshape(8, 3),
+               "b": jax.numpy.arange(8.0)}
+    metrics = {"train_loss": jax.numpy.arange(8.0)}
+    pulled = [[{"x": np.zeros((2, 1))}]] * 6      # 6 clients, 2 filler rows
+    out = ex._split(stacked, metrics, [None] * 6, pulled)
+    assert len(out) == 6
+    for i, (t, n_seen, m) in enumerate(out):
+        assert t["w"].shape == (3,) and t["b"].shape == ()
+        assert np.array_equal(np.asarray(t["w"]), np.asarray(stacked["w"][i]))
+        assert float(t["b"]) == i and m == {"train_loss": float(i)} and n_seen == 2.0
+    built = _unstack._cache_size()
+    ex._split(stacked, metrics, [None] * 5, pulled[:5])
+    assert _unstack._cache_size() == built
+
+
+@pytest.mark.parametrize("sizes", [[4] * 5, [4, 0, 6, 2]], ids=["dense", "ragged"])
+def test_wave_moves_only_the_metrics_to_the_host(sizes):
+    from repro.obs import ObsPlane
+
+    obs = ObsPlane()
+    ex = BatchedExecutor(MCFG, OPT, obs=obs, tenant="t")
+    cl, params = _world(sizes, seed=2)
+    res = ex.run_wave(params, cl, 2)
+    n_metrics = len(res[0][2])
+    assert obs.registry.counter("client.d2h_bytes", "t").value == len(sizes) * n_metrics * 4
+
+
 def test_wave_program_cache_reused_across_row_splits():
     """Group sizes are traced, so two ragged waves with the same
     (clients, steps, rows, width) envelope but different per-client row
@@ -343,6 +403,7 @@ a = plain.run_wave(params, cl, 3, round_idx=1)
 cl, params = _world([4] * 6, seed=11)
 b = sharded.run_wave(params, cl, 3, round_idx=1)
 assert plain.last_wave['mode'] == sharded.last_wave['mode'] == 'dense'
+assert len(a) == len(b) == 6                   # the filler is not a client
 diff = _max_delta_diff(a, b)
 print('DIFF', diff)
 assert diff == 0.0, diff

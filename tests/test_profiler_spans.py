@@ -86,7 +86,8 @@ def _inside(child, parent):
 
 def _wave_bytes(mode, tr, rec):
     """(h2d, d2h) of the round's wave, from shapes: the wave's host inputs;
-    every client's delta (the parameters' bytes) and float32 metrics."""
+    every client's float32 metrics (the deltas stay on the device); and
+    the parameters' bytes, one client's delta."""
     sizes = BATCHES[mode]
     rows = sum(sizes)
     x_row, f32, i32 = 8 * 8 * 1 * 4, 4, 4
@@ -96,7 +97,7 @@ def _wave_bytes(mode, tr, rec):
         h2d += CLIENTS * i32 + rows * i32                      # group sizes, segment ids
     param_bytes = sum(a.nbytes for a in jax.tree.leaves(tr.params))
     n_metrics = sum(1 for k in rec if k.startswith("train_"))
-    return h2d, CLIENTS * param_bytes + CLIENTS * n_metrics * f32, param_bytes
+    return h2d, CLIENTS * n_metrics * f32, param_bytes
 
 
 @pytest.mark.parametrize("mode", ["dense", "ragged"])
@@ -127,7 +128,7 @@ def test_profiled_round_has_every_span_nested_with_args(tmp_path, mode):
     assert launch[3] == {"rows": sum(BATCHES[mode]), "h2d_bytes": h2d}
     assert fetch[3] == {"d2h_bytes": d2h}
     assert batch[3]["clients"] == CLIENTS and batch[3]["mode"] == mode
-    assert fold[0][3] == {"deltas": CLIENTS, "bytes": CLIENTS * param_bytes}
+    assert fold[0][3] == {"deltas": CLIENTS, "bytes": CLIENTS * param_bytes, "h2d_bytes": 0}
     assert agg[3] == {"round": 0, "deltas": CLIENTS}
 
 
@@ -158,6 +159,8 @@ def test_tracer_holds_the_same_spans_and_counters_match(tmp_path, mode):
     fetch = [a for n, a in prof if n == "wave.fetch"]
     assert reg.counter("client.h2d_bytes", tr.tenant).value == sum(a["h2d_bytes"] for a in launch)
     assert reg.counter("client.d2h_bytes", tr.tenant).value == sum(a["d2h_bytes"] for a in fetch)
+    fold_sum = [a for n, a in prof if n == "fold.sum"]
+    assert reg.counter("fold.h2d_bytes", tr.tenant).value == sum(a["h2d_bytes"] for a in fold_sum)
     # a wave has no per-client training time
     assert reg.histogram("client.train_seconds", tr.tenant).snapshot()["count"] == 0
 

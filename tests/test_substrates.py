@@ -11,7 +11,8 @@ except ImportError:  # dev extra absent: deterministic mini-sampler
     from _hypothesis_fallback import given, settings, strategies as st
 
 from repro.ckpt.checkpoint import CheckpointManager, restore_pytree, save_pytree
-from repro.core.aggregation import AsyncAggregator, apply_deltas, fedavg, tree_sub
+from repro.core.aggregation import (FOLD_CHUNK, AsyncAggregator, apply_deltas, fedavg,
+                                    tree_sub)
 from repro.data.partition import dirichlet_partition, partition_stats
 from repro.data.pipeline import ClientDataset
 from repro.data.synthetic import make_dataset
@@ -163,6 +164,77 @@ def test_apply_deltas_moves_params():
     delta = {"w": jnp.ones((2,))}
     out = apply_deltas(params, [(delta, 1.0)], server_lr=0.5)
     np.testing.assert_allclose(np.asarray(out["w"]), [0.5, 0.5])
+
+
+def _eager_fold(updates):
+    """FedAvg's weighted sum as an eager float32 loop: scale, then add in
+    client order."""
+    total = sum(w for _, w in updates)
+    acc = None
+    for t, w in updates:
+        s = np.float32(w / total)
+        term = jax.tree.map(lambda x: jnp.asarray(x, jnp.float32) * s, t)
+        acc = term if acc is None else jax.tree.map(jnp.add, acc, term)
+    return acc
+
+
+def _mixed_updates(seed, n=5, host=False):
+    rng = np.random.default_rng(seed)
+    updates = []
+    for _ in range(n):
+        t = {"w": rng.normal(size=(6, 4)).astype(np.float32),
+             "b": rng.normal(size=(4,)).astype(np.float32)}
+        updates.append((t if host else jax.tree.map(jnp.asarray, t),
+                        float(rng.integers(1, 300))))
+    return updates
+
+
+@pytest.mark.parametrize("n", [5, 2 * FOLD_CHUNK + 5], ids=["one_chunk", "three_chunks"])
+@pytest.mark.parametrize("host", [False, True], ids=["device", "numpy"])
+def test_fedavg_matches_eager_float32_fold(host, n):
+    from repro.obs.metrics import Counter
+
+    updates = _mixed_updates(4, n=n, host=host)
+    ref = _eager_fold(updates)
+    avg = fedavg(updates)
+    for k in ref:
+        assert isinstance(avg[k], jax.Array) and avg[k].dtype == jnp.float32
+        np.testing.assert_allclose(np.asarray(avg[k]), np.asarray(ref[k]), rtol=1e-6,
+                                   atol=1e-7)
+    h2d = Counter()
+    params = {"w": jnp.zeros((6, 4)), "b": jnp.zeros((4,))}
+    apply_deltas(params, updates, h2d=h2d)
+    host_bytes = sum(a.nbytes for t, _ in updates for a in jax.tree.leaves(t))
+    assert h2d.value == (host_bytes if host else 0)
+
+
+def test_fold_chunk_padding_adds_exactly_nothing():
+    """A short chunk is padded with its last delta at scale 0: the sum is
+    bit for bit the one padded with zero trees."""
+    from repro.core.aggregation import _fold_chunk
+
+    updates = _mixed_updates(5, n=3)
+    avg = fedavg(updates)
+    total = sum(w for _, w in updates)
+    scales = np.zeros(FOLD_CHUNK, np.float32)
+    scales[:3] = [w / total for _, w in updates]
+    zeros = jax.tree.map(jnp.zeros_like, updates[0][0])
+    ref = _fold_chunk(None, scales, tuple([t for t, _ in updates] + [zeros] * (FOLD_CHUNK - 3)))
+    for k in ref:
+        assert np.array_equal(np.asarray(avg[k]), np.asarray(ref[k]))
+
+
+def test_fedavg_programs_do_not_depend_on_weights_or_count():
+    """New weights and another number of deltas (one chunk or several)
+    reuse the fold's programs: a round whose finisher count varies builds
+    nothing."""
+    from repro.core.aggregation import _fold_chunk
+
+    fedavg(_mixed_updates(1, n=FOLD_CHUNK + 1))        # first and later chunks
+    built = _fold_chunk._cache_size()
+    for seed, n in [(2, 3), (3, 4), (4, FOLD_CHUNK), (5, 3 * FOLD_CHUNK + 5)]:
+        fedavg(_mixed_updates(seed, n=n))
+    assert _fold_chunk._cache_size() == built
 
 
 def test_async_buffer_staleness_discount():
