@@ -65,7 +65,7 @@ def main(argv=None) -> int:
 
         def follow(rounds, n=1, **kw):
             return replay.follow(ref, m, theta0, rounds, steps, lr, pool.test,
-                                 norms_rounds=range(n), **kw)
+                                 norms_rounds=range(n), **replay.local_rule(traffic), **kw)
 
         n_after = after.n_rounds
         sides = [("program", rec.program, after.program)]

@@ -9,7 +9,7 @@ Nothing is recomputed; elementwise work is not counted.
 
 The least bytes of a step are the work the algorithm needs whatever the
 implementation: the client's parameters read once and written once, plus
-its batch read once.
+its batch read once, as the family feeds it.
 """
 from __future__ import annotations
 
@@ -33,18 +33,15 @@ def n_params(ref, m: Dict[str, Any]) -> int:
     return int(sum(np.prod(s.shape) for s in jax.tree.leaves(shapes)))
 
 
-def example_bytes(m: Dict[str, Any], x_bytes: int = 4, y_bytes: int = 4) -> int:
-    """One example as the program feeds it: float32 pixels, int32 label."""
-    return m["image_size"] * m["image_size"] * m["channels"] * x_bytes + y_bytes
-
-
 class StepCounts:
-    """Totals over the client steps a window completed."""
+    """Totals over the client steps a window completed.  ``example_bytes``
+    is one example as the program feeds it (the family's
+    ``example_bytes``)."""
 
-    def __init__(self, ref, m: Dict[str, Any], param_bytes: int = 4):
+    def __init__(self, ref, m: Dict[str, Any], example_bytes: int, param_bytes: int = 4):
         self.flops_per_example = train_flops_per_example(ref, m)
         self.param_rw_bytes = 2 * param_bytes * n_params(ref, m)
-        self.example_bytes = example_bytes(m)
+        self.example_bytes = example_bytes
         self.examples = 0
         self.steps = 0
 

@@ -1,6 +1,11 @@
-"""The one traffic generator: a client pool and its data, from a traffic
-mix (``traffic/<mix>.json``), a model configuration (``configs/<name>.json``)
-and a seed.
+"""What every family of models draws its client pool from, and the
+participants of the rounds checked after the window.
+
+A family module (``families/<family>.py``) makes a cell's pool from its
+traffic mix (``traffic/<mix>.json``), its model configuration
+(``configs/<name>.json``) and a seed, with the helpers here: the seed
+streams, FedScale's compute budgets, the shard sizes, non-IID labels and
+the per-client batch sizes.
 
 Everything is drawn from ``numpy.random.SeedSequence(seed)``, so any whole
 number is a valid seed and the same seed gives the same pool.  Every seed
@@ -10,43 +15,32 @@ the seed changes which client gets which and not how much work the pool
 holds.
 
 Shard sizes are the quantiles of a lognormal with the configuration's
-mean and standard deviation of samples per client (LEAF FEMNIST's, scaled
-to the cut mean).  Budgets are the quantiles of FedScale's long-tailed device
-speeds, the distribution of the program's
-``repro.core.budget.fedscale_budget_distribution``.  Each client's labels
-are non-IID: its class shares are drawn from Dir(alpha) over the classes
-(Hsu et al., arXiv:1909.06335).  The images are made on the device in
-fixed-size chunks, one jitted call each, pulled to the host chunk by
-chunk, so that set-up never holds more than :data:`CHUNK_ROWS` images on
-the device: class-conditional Gaussians over a rank-32 basis, the shape of
-the program's synthetic FEMNIST.
+mean and standard deviation of samples per client.  Budgets are the
+quantiles of FedScale's long-tailed device speeds, the distribution of the
+program's ``repro.core.budget.fedscale_budget_distribution``.  Each
+client's labels are non-IID: its class shares are drawn from Dir(alpha)
+over the classes (Hsu et al., arXiv:1909.06335).
 """
 from __future__ import annotations
 
-import functools
 from dataclasses import dataclass
 from statistics import NormalDist
 from typing import Any, Dict, List, Sequence, Tuple
 
 import numpy as np
 
-#: rank of the class-mean basis and the class separation of the images
-IMAGE_RANK = 32
-CLASS_SEP = 8.0
-#: images drawn in one device call (8192 FEMNIST images are 25.7 MB)
-CHUNK_ROWS = 8192
-
-
 @dataclass
 class Pool:
-    """What one run's clients are made of: every array is host numpy."""
+    """What one run's clients are made of: every array is host numpy.  A
+    client's examples are ``x`` (its family's inputs: images, token
+    sequences) and ``y`` (one label each)."""
 
-    x: List[np.ndarray]          # per client, (n_c, H, W, C) float32
+    x: List[np.ndarray]          # per client, (n_c, ...)
     y: List[np.ndarray]          # per client, (n_c,) int32
     batch_sizes: np.ndarray      # (pool,) int
     budgets: np.ndarray          # (pool,) percent of the pool's compute
     data_seeds: np.ndarray       # (pool,) per-client shuffle seeds
-    test: Dict[str, np.ndarray]  # {"x": (T, H, W, C), "y": (T,)}
+    test: Dict[str, np.ndarray]  # {"x": (T, ...), "y": (T,)}
     fed_seed: int                # the trainer's own seed (< 2**31)
     weight_key: int              # seed of the model's initial weights
 
@@ -108,73 +102,6 @@ def batch_assignment(rng: np.random.Generator, mix: Sequence[Sequence[float]],
     out = np.repeat(np.asarray(sizes, np.int64), counts)
     rng.shuffle(out)
     return out
-
-
-@functools.lru_cache(maxsize=None)
-def _chunk_fn(h: int, c: int, k: int):
-    """The jitted draw of one chunk of :data:`CHUNK_ROWS` images."""
-    import jax
-    import jax.numpy as jnp
-
-    dim = h * h * c
-
-    @jax.jit
-    def draw(key, i, y):
-        kb, kp, kn = jax.random.split(key, 3)
-        basis = jax.random.normal(kb, (k, IMAGE_RANK), jnp.float32)
-        proj = jax.random.normal(kp, (IMAGE_RANK, dim), jnp.float32) / np.sqrt(IMAGE_RANK)
-        means = (basis @ proj) * (CLASS_SEP / np.sqrt(dim))
-        noise = jax.random.normal(jax.random.fold_in(kn, i), (y.shape[0], dim), jnp.float32)
-        return (means[y] + noise).reshape(y.shape[0], h, h, c)
-
-    return draw
-
-
-def make_images(seed: int, labels: np.ndarray, data: Dict[str, int]) -> np.ndarray:
-    """Images for ``labels``: class means over a rank-32 basis plus unit
-    Gaussian noise, drawn on the default device :data:`CHUNK_ROWS` at a
-    time (the last chunk padded) and gathered on the host."""
-    import jax
-
-    h, c = data["image_size"], data["channels"]
-    draw = _chunk_fn(h, c, data["classes"])
-    key = jax.random.key(seed)
-    out = np.empty((len(labels), h, h, c), np.float32)
-    y = np.zeros(CHUNK_ROWS, np.int32)
-    for i, lo in enumerate(range(0, len(labels), CHUNK_ROWS)):
-        m = min(CHUNK_ROWS, len(labels) - lo)
-        y[:m], y[m:] = labels[lo:lo + m], 0
-        out[lo:lo + m] = np.asarray(jax.device_get(draw(key, i, y)))[:m]
-    return out
-
-
-def make_pool(seed: int, traffic: Dict[str, Any], config: Dict[str, Any]) -> Pool:
-    r_lab, r_split, r_batch, r_budget, r_seeds, r_misc = seed_streams(seed, 6)
-    data = config["data"]
-    n = int(traffic["pool_clients"])
-    n_test = int(traffic["test_samples"])
-    sizes = r_split.permutation(shard_sizes(n, float(config["samples_per_client"]),
-                                            float(config["samples_per_client_sd"])))
-    parts = client_labels(r_split, sizes, int(data["classes"]),
-                          float(traffic["dirichlet_alpha"]))
-    labels = np.concatenate(parts + [r_lab.integers(0, data["classes"], size=n_test)
-                                     .astype(np.int32)])
-    images = make_images(int(r_misc.integers(2 ** 31)), labels, data)
-    # the model's classes: a 10-class model reads the FEMNIST writers'
-    # labels mod 10, as digits
-    classes = labels % int(config["model"]["n_classes"])
-    n_train = int(sizes.sum())
-    cuts = np.cumsum(sizes)[:-1]
-    return Pool(
-        x=np.split(images[:n_train], cuts),
-        y=np.split(classes[:n_train], cuts),
-        batch_sizes=batch_assignment(r_batch, traffic["batch_mix"], n),
-        budgets=r_budget.permutation(fedscale_budgets(n)),
-        data_seeds=r_seeds.integers(0, 2 ** 31, size=n),
-        test={"x": images[n_train:], "y": classes[n_train:]},
-        fed_seed=int(r_misc.integers(2 ** 31)),
-        weight_key=int(r_misc.integers(2 ** 31)),
-    )
 
 
 def composition_range(traffic: Dict[str, Any], sigmas: float = 4.5) -> List[Tuple[int, ...]]:

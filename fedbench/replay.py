@@ -15,13 +15,17 @@ stretch, and, after the window, every client's delta itself.
 
 After the window has closed and the program's state is freed,
 :func:`follow` replays each stretch in plain ``jax.numpy``: each finisher
-pulls the same batches from its copy and takes ``local_steps`` SGD steps
-on the mean cross-entropy, with the gradient clipped to a global norm of
-10, from the round's global parameters; the deltas are folded by
-weighted FedAvg (weight = examples seen).  It imports nothing of the
-program's models, executor or aggregation: it shares with the program
-only the inputs (the data, the initial weights the benchmark made, the
-finishers the trainer picked).
+pulls the same batches from its copy and takes ``local_steps`` steps from
+the round's global parameters, with the gradient clipped to a global norm
+of 10, on the reference's ``loss`` where it defines one and otherwise on
+the mean cross-entropy of its ``logits`` against one label per example;
+the deltas are folded by weighted FedAvg (weight = examples seen).  The
+step is the mix's ``fed.optimizer`` (:func:`local_rule`): SGD, or AdamW
+(Adam's moments zeroed for every client every round, bias-corrected, no
+weight decay), the program's documented rules.  It imports
+nothing of the program's models, optimizers, executor or aggregation: it
+shares with the program only the inputs (the data, the initial weights
+the benchmark made, the finishers the trainer picked).
 
 :func:`compare` reduces the two sides to six numbers, each a gap of the
 program from the reference; ``limits/<cell>.json`` holds the limit of
@@ -41,6 +45,10 @@ HERE = Path(__file__).resolve().parent
 
 #: the global-norm clip of a local step (the program's documented rule)
 CLIP_NORM = 10.0
+#: AdamW's decay rates and epsilon (the program's documented rule)
+ADAM_B1, ADAM_B2, ADAM_EPS = 0.9, 0.95, 1e-8
+#: the local rules the replay knows
+OPTIMIZERS = ("sgd", "adamw")
 #: a leaf whose reference update is under this share of the median leaf's
 #: moves by round-off alone and is left out of the gaps of norms
 NEGLIGIBLE_LEAF = 1e-3
@@ -167,16 +175,26 @@ class Recorder:
         self.program.thetas.append(jax.device_get(tr.params["main"]))
 
 
+def local_rule(traffic: Dict[str, Any]) -> Dict[str, Any]:
+    """The local step a mix's ``fed`` knobs give the program, as
+    :func:`follow`'s keywords: the optimizer (``FedConfig``'s default
+    "sgd"; the trainer builds AdamW without weight decay)."""
+    return {"optimizer": traffic.get("fed", {}).get("optimizer", "sgd")}
+
+
 def follow(ref, m: Dict[str, Any], theta0_host, rounds: List[CheckedRound],
            steps: int, lr: float, test: Dict[str, np.ndarray], *,
+           optimizer: str = "sgd",
            dtype: str = "float32", precision: Optional[str] = "highest",
            fault: Optional[str] = None, norms_rounds: Sequence[int] = (0,),
            delta_rounds: Sequence[int] = (), against: Sequence[Readings] = ()) -> Readings:
-    """A stretch of rounds in plain ``jax.numpy``.  ``dtype``/``precision``
-    set the arithmetic (the reference: float32 at "highest"; the control:
-    bfloat16 throughout).  ``fault`` plants one of the faults the check
-    must catch: ``"half_batch"`` (each step's mean over the first half of
-    its batch) or ``"unchanged"`` (every round leaves the model as it
+    """A stretch of rounds in plain ``jax.numpy``.  ``optimizer`` is the
+    local rule (:func:`local_rule`).
+    ``dtype``/``precision`` set the arithmetic (the reference: float32 at
+    "highest"; the control: bfloat16 throughout, integer inputs as they
+    are).  ``fault`` plants one of the faults the check must catch:
+    ``"half_batch"`` (each step's mean over the first half of its batch,
+    every key cut) or ``"unchanged"`` (every round leaves the model as it
     was).  For each side in ``against`` that kept a round's client deltas,
     the per-leaf cosine of each with this side's delta is written into
     that side's ``client_cos``; ``delta_rounds`` keeps this side's own."""
@@ -184,30 +202,58 @@ def follow(ref, m: Dict[str, Any], theta0_host, rounds: List[CheckedRound],
     import jax.numpy as jnp
     from jax import lax
 
+    if optimizer not in OPTIMIZERS:
+        raise ValueError(f"the replay has no local rule {optimizer!r}")
     dt = jnp.dtype(dtype)
     prec = None if precision in (None, "default") else precision
 
-    def ce(p, x, y):
-        z = ref.logits(p, x, m, prec)
-        return jnp.mean(jax.nn.logsumexp(z, -1)
-                        - jnp.take_along_axis(z, y[:, None], -1)[:, 0])
+    def cast(a):
+        return jnp.asarray(a, dt) if np.issubdtype(a.dtype, np.floating) else jnp.asarray(a)
 
-    def step(p, xy):
-        x, y = xy
+    def loss(p, batch):
+        if hasattr(ref, "loss"):
+            return ref.loss(p, batch, m, prec)
+        z = ref.logits(p, batch["x"], m, prec)
+        return jnp.mean(jax.nn.logsumexp(z, -1)
+                        - jnp.take_along_axis(z, batch["y"][:, None], -1)[:, 0])
+
+    def grad(p, batch):
+        """The step's gradient and the scale of its global-norm clip."""
         if fault == "half_batch":
-            x, y = x[: x.shape[0] // 2], y[: y.shape[0] // 2]
-        g = jax.grad(ce)(p, x, y)
+            batch = {k: v[: v.shape[0] // 2] for k, v in batch.items()}
+        g = jax.grad(loss)(p, batch)
         norm = jnp.sqrt(sum(jnp.sum(jnp.square(a)) for a in jax.tree.leaves(g)))
-        scale = jnp.minimum(1.0, CLIP_NORM / jnp.maximum(norm, 1e-9)).astype(dt)
+        return g, jnp.minimum(1.0, CLIP_NORM / jnp.maximum(norm, 1e-9)).astype(dt)
+
+    def sgd(p, batch):
+        g, scale = grad(p, batch)
         return jax.tree.map(lambda a, b: a - (lr * scale) * b, p, g), None
+
+    def adamw(state, batch):
+        p, mu, nu, t = state
+        g, scale = grad(p, batch)
+        g = jax.tree.map(lambda a: a * scale, g)
+        t = t + 1
+        mu = jax.tree.map(lambda a, b: ADAM_B1 * a + (1 - ADAM_B1) * b, mu, g)
+        nu = jax.tree.map(lambda a, b: ADAM_B2 * a + (1 - ADAM_B2) * jnp.square(b), nu, g)
+        c1, c2 = 1.0 - ADAM_B1 ** t, 1.0 - ADAM_B2 ** t
+
+        def update(a, u, v):
+            return a - lr * ((u / c1) / (jnp.sqrt(v / c2) + ADAM_EPS))
+
+        return (jax.tree.map(update, p, mu, nu), mu, nu, t), None
 
     def norms(d):
         return jnp.stack([jnp.sqrt(jnp.sum(jnp.square(a.astype(jnp.float32))))
                           for a in jax.tree.leaves(d)])
 
     @jax.jit
-    def local(p, xs, ys):
-        q, _ = lax.scan(step, p, (xs, ys))
+    def local(p, batches):
+        if optimizer == "sgd":
+            q, _ = lax.scan(sgd, p, batches)
+        else:
+            zeros = jax.tree.map(jnp.zeros_like, p)
+            (q, _, _, _), _ = lax.scan(adamw, (p, zeros, zeros, jnp.zeros((), dt)), batches)
         d = jax.tree.map(jnp.subtract, q, p)
         return d, norms(d)
 
@@ -227,9 +273,8 @@ def follow(ref, m: Dict[str, Any], theta0_host, rounds: List[CheckedRound],
 
     # the test set is an argument, not a constant, so that one compiled
     # program serves every seed
-    tx = jnp.asarray(test["x"], dt)
-    ty = jnp.asarray(test["y"])
-    test_loss = jax.jit(ce)
+    test_batch = {k: cast(v) for k, v in test.items()}
+    test_loss = jax.jit(loss)
 
     theta = jax.tree.map(lambda a: jnp.asarray(a, dt), theta0_host)
     out = Readings(theta0=jax.device_get(theta))
@@ -245,12 +290,12 @@ def follow(ref, m: Dict[str, Any], theta0_host, rounds: List[CheckedRound],
         if fault != "unchanged":
             data = copy.deepcopy(rnd.data)
             pulled = [[ds.next_batch() for _ in range(steps)] for ds in data]
-            weights = np.asarray([steps * b[0]["x"].shape[0] for b in pulled], np.float64)
+            weights = np.asarray([steps * len(next(iter(b[0].values()))) for b in pulled],
+                                 np.float64)
             acc = jax.tree.map(jnp.zeros_like, theta)
             for i, (batches, w) in enumerate(zip(pulled, weights / weights.sum())):
-                xs = jnp.asarray(np.stack([b["x"] for b in batches]), dt)
-                ys = jnp.asarray(np.stack([b["y"] for b in batches]))
-                d, n = local(theta, xs, ys)
+                d, n = local(theta, {k: cast(np.stack([b[k] for b in batches]))
+                                     for k in batches[0]})
                 if r in norms_rounds:
                     out.client_norms[r].append(n)
                 if r in delta_rounds:
@@ -268,7 +313,7 @@ def follow(ref, m: Dict[str, Any], theta0_host, rounds: List[CheckedRound],
             for s in sides:
                 # a zero delta has no direction: it turns by a right angle
                 s.client_cos[r] = [np.zeros(n_leaves) for _ in rnd.cids]
-        out.test_losses.append(float(test_loss(theta, tx, ty)))
+        out.test_losses.append(float(test_loss(theta, test_batch)))
         out.thetas.append(jax.device_get(theta))
     out.client_norms = {r: [np.asarray(v, np.float64) for v in jax.device_get(vs)]
                         for r, vs in out.client_norms.items()}

@@ -5,19 +5,22 @@
 
 A cell of ``BENCHMARK.json`` names a model configuration
 (``configs/<name>.json``, found through the cell's config entry) and a
-traffic mix (``traffic/<mix>.json``).  The run:
+traffic mix (``traffic/<mix>.json``).  The configuration's ``"family"``
+(``"image"`` where it names none) is the module ``families/<family>.py``
+that makes the pool and the trainer and warms them; the model's
+``kind`` names its plain reference, ``reference/<kind>.py``.  The run:
 
 1. refuses to run (exit 2, no result) unless JAX's first device is a TPU
    whose ``device_kind`` is in ``peaks.json`` and there are as many chips
    as the cell asks for;
-2. set-up: makes the client pool and its data (``generate.py``) and the
-   initial weights (``reference/<kind>.py``) from the seed, builds
-   ``FederatedTrainer`` with the mix's ``FedConfig`` knobs, and drives it
-   through the mix's checked rounds with the window's own calls while
-   ``replay.Recorder`` keeps what the check needs; where the executor ran
-   a ragged wave, every per-round composition of batch sizes the pool can
-   plausibly draw is warmed too (the program compiles one ragged program
-   per total row count);
+2. set-up: makes the client pool and its data (the family's
+   ``make_pool``) and the initial weights (the reference's ``init``) from
+   the seed, builds ``FederatedTrainer`` with the mix's ``FedConfig``
+   knobs (the family's ``build_trainer``), and drives it through the mix's
+   checked rounds with the window's own calls while ``replay.Recorder``
+   keeps what the check needs; then the family warms whatever else the
+   window can build (``warm``: for the image family, every per-round
+   composition of batch sizes of a ragged wave);
 3. the window: whole rounds through ``begin_round``/``step_round``, each
    phase timed on the host clock (and, with ``--trace 1``, wrapped in a
    ``round.<phase>`` profiler annotation), until ``--seconds`` have passed
@@ -34,7 +37,11 @@ traffic mix (``traffic/<mix>.json``).  The run:
 
 With ``--trace 0`` the line's metrics are the cell's end-to-end metrics,
 with ``--trace 1`` its per-layer metrics; each is read by
-``metrics/<name>.py`` from the window's timers, counters and trace.
+``metrics/<name>.py`` from the window's timers, counters and trace.  The
+trace is reduced by ``span_reduce.py``, which reads the program's
+``fedhc.*`` spans besides the device's work; a traced run's line also
+holds, under ``run``, the seconds, counts and summed args of each span
+and the device's idle time by the innermost span open.
 """
 from __future__ import annotations
 
@@ -73,6 +80,16 @@ def _in_cell(metric: Dict[str, Any], cell: str) -> bool:
     return "workloads" not in metric or cell in metric["workloads"]
 
 
+def load_family(name: str, root: Path):
+    """The family module ``families/<name>.py`` of the checkout at ``root``."""
+    from replay import load_module
+
+    path = root / "fedbench" / "families" / f"{name}.py"
+    if not path.exists():
+        raise Refused(f"no family {name!r}: {path.name} is not in fedbench/families")
+    return load_module(path, f"fedbench_family_{name}")
+
+
 def load_cell(name: str, root: Path, need_limits: bool = True) -> Dict[str, Any]:
     """Everything one cell is made of, found by name.  A cell is refused
     unless its limits give every number a limit or the reason it is not
@@ -91,9 +108,11 @@ def load_cell(name: str, root: Path, need_limits: bool = True) -> Dict[str, Any]
                and "not_compared" not in (limits.get(k) or {})]
     if missing and need_limits:
         raise Refused(f"no limit for {', '.join(missing)} in {limits_path.name}")
+    config = load_json(root / entry["file"])
     return {
         "cell": cell,
-        "config": load_json(root / entry["file"]),
+        "config": config,
+        "family": load_family(config.get("family", "image"), root),
         "traffic": load_json(root / "fedbench" / "traffic" / f"{cell['traffic']}.json"),
         "end_to_end": [m for m in bench["end_to_end"] if _in_cell(m, name)],
         "per_layer": [m for m in bench["per_layer"] if _in_cell(m, name)],
@@ -125,28 +144,11 @@ def build_trainer(spec: Dict[str, Any], seed: int):
     """The pool, the trainer and the initial weights, all from ``seed``."""
     import jax
 
-    from generate import make_pool
     from replay import load_reference
-    from repro.core.budget import WorkloadSpec
-    from repro.data.pipeline import ClientDataset
-    from repro.fed.client import FLClient
-    from repro.fed.trainer import FedConfig, FederatedTrainer
-    from repro.models.small import SmallModelConfig
 
-    config, traffic = spec["config"], spec["traffic"]
-    m = config["model"]
-    steps = int(traffic["local_steps"])
-    pool = make_pool(seed, traffic, config)
-    clients = [
-        FLClient(cid, float(pool.budgets[cid]),
-                 ClientDataset(pool.x[cid], pool.y[cid], int(pool.batch_sizes[cid]),
-                               seed=int(pool.data_seeds[cid])),
-                 WorkloadSpec(model=m["kind"], n_layers=m["n_layers"],
-                              batch_size=int(pool.batch_sizes[cid]), n_batches=steps))
-        for cid in range(len(pool.x))]
-    fed = FedConfig(rounds=1 << 30, participants_per_round=int(traffic["participants_per_round"]),
-                    local_steps=steps, seed=pool.fed_seed, **traffic["fed"])
-    tr = FederatedTrainer(SmallModelConfig(**m), clients, fed, test_batch=pool.test)
+    m = spec["config"]["model"]
+    pool = spec["family"].make_pool(seed, spec["traffic"], spec["config"])
+    tr = spec["family"].build_trainer(spec, pool)
     ref = load_reference(m["kind"], spec["root"] / "fedbench")
     theta0 = jax.jit(lambda k: ref.init(k, m))(jax.random.key(pool.weight_key))
     own = tr.params["main"]
@@ -156,31 +158,6 @@ def build_trainer(spec: Dict[str, Any], seed: int):
         raise RuntimeError("the program's parameter layout differs from the reference's")
     tr.params = {"main": theta0}
     return tr, pool, ref
-
-
-def warm_compositions(tr, traffic: Dict[str, Any], config: Dict[str, Any]) -> int:
-    """Run one wave through the trainer's own executor for every per-round
-    composition of batch sizes the pool can plausibly draw, so that no
-    program is built inside the window.  Returns the waves run."""
-    import numpy as np
-
-    from generate import composition_range
-    from repro.data.pipeline import ClientDataset
-    from repro.fed.client import FLClient
-
-    d = config["data"]
-    shape = (d["image_size"], d["image_size"], d["channels"])
-    sizes = [int(b) for b, _ in traffic["batch_mix"]]
-    steps = int(traffic["local_steps"])
-    n = 0
-    for comp in composition_range(traffic):
-        bs = [b for b, k in zip(sizes, comp) for _ in range(k)]
-        fakes = [FLClient(i, 50.0, ClientDataset(np.zeros((b,) + shape, np.float32),
-                                                 np.zeros((b,), np.int32), b))
-                 for i, b in enumerate(bs)]
-        tr.batch_exec.run_wave(tr.params, fakes, steps, 0)
-        n += 1
-    return n
 
 
 def checked_rounds(tr, recorder, n: int) -> None:
@@ -319,6 +296,7 @@ def main(argv: Optional[List[str]] = None, *, root: Path = ROOT, require_tpu: bo
 
     import flops
     import replay
+    import span_reduce
     import trace_reduce
     from repro.launch.compile_cache import enable_compile_cache
 
@@ -327,7 +305,7 @@ def main(argv: Optional[List[str]] = None, *, root: Path = ROOT, require_tpu: bo
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
-    config, traffic = spec["config"], spec["traffic"]
+    config, traffic, family = spec["config"], spec["traffic"], spec["family"]
     precision = config.get("matmul_precision", "default")
 
     def prec_ctx():
@@ -342,16 +320,14 @@ def main(argv: Optional[List[str]] = None, *, root: Path = ROOT, require_tpu: bo
         recorder = replay.Recorder(theta0_host, int(traffic["checked_rounds"]))
         checked_rounds(tr, recorder, recorder.n_rounds)
         marks["checked_rounds"] = time.perf_counter() - t_start
-        warmed = 0
-        if tr.batch_exec is not None and tr.batch_exec.last_wave.get("mode") == "ragged":
-            warmed = warm_compositions(tr, traffic, config)
+        warmed = family.warm(tr, traffic, config)
         jax.block_until_ready(tr.params)
         # the set-up's garbage is collected here, not in the window's first rounds
         gc.collect()
         setup_s = time.perf_counter() - t_start
         marks["warmed"] = setup_s
 
-        counts = flops.StepCounts(ref, config["model"])
+        counts = flops.StepCounts(ref, config["model"], family.example_bytes(config["model"]))
         log_dir = None
         if args.trace:
             log_dir = tempfile.mkdtemp(prefix="fedbench_trace_")
@@ -372,7 +348,7 @@ def main(argv: Optional[List[str]] = None, *, root: Path = ROOT, require_tpu: bo
     summary = None
     if log_dir is not None:
         xp = trace_reduce.find_xplane(log_dir)
-        summary = trace_reduce.reduce_file(xp) if xp else None
+        summary = span_reduce.reduce_file(xp) if xp else None
         shutil.rmtree(log_dir, ignore_errors=True)
 
     # the program's state goes before the reference runs
@@ -380,18 +356,20 @@ def main(argv: Optional[List[str]] = None, *, root: Path = ROOT, require_tpu: bo
     del tr
     gc.collect()
     t_ref = time.perf_counter()
+    rule = replay.local_rule(traffic)
     ref_setup = replay.follow(ref, config["model"], theta0_host, recorder.rounds, steps, lr,
-                              pool.test)
+                              pool.test, **rule)
     ref_after = replay.follow(ref, config["model"], theta0_host, after.rounds, steps, lr,
                               pool.test, norms_rounds=range(after.n_rounds),
-                              against=[after.program])
+                              against=[after.program], **rule)
     numbers = replay.compare([recorder.program, after.program], [ref_setup, ref_after])
     checks = replay.judge(numbers, spec["limits"])
     correct = replay.passed(checks)
     marks["reference_s"] = time.perf_counter() - t_ref
 
     ctx = {**w, "setup_s": setup_s, "trace": summary, "peak": peaks.get(dev.device_kind),
-           "memory_peak_bytes": memory_peak, "counts": counts}
+           "memory_peak_bytes": memory_peak, "counts": counts,
+           "step_programs": family.STEP_PROGRAMS}
     metrics = {}
     for mdef in spec["per_layer" if args.trace else "end_to_end"]:
         mod = replay.load_module(root / "fedbench" / "metrics" / f"{mdef['name']}.py",
@@ -414,6 +392,8 @@ def main(argv: Optional[List[str]] = None, *, root: Path = ROOT, require_tpu: bo
         device["busy_s"] = summary.busy_s
         device["window_s"] = summary.window_s
         result["breakdown"] = {"device_ops": summary.top_ops(), "idle_gaps": summary.top_idle()}
+        for key in ("idle_by_span", "span_s", "span_n", "span_args"):
+            result["run"][key] = getattr(summary, key)
     result["checks"] = checks
     for k, c in checks.items():
         print(f"check {k}: {c['value']!r} limit {c['limit']!r}", file=sys.stderr)

@@ -5,9 +5,11 @@ from pathlib import Path
 import pytest
 
 import flops
+import run
 from replay import load_reference
 
-CONFIGS = Path(__file__).resolve().parents[1] / "configs"
+FEDBENCH = Path(__file__).resolve().parents[1]
+CONFIGS = FEDBENCH / "configs"
 
 
 def model(name):
@@ -36,7 +38,7 @@ def test_fedavg_2nn_counts():
 @pytest.mark.parametrize("name,kind", [("femnist_cnn", "cnn"), ("fedavg_2nn", "mlp")])
 def test_step_counts_least_time(name, kind):
     m, ref = model(name), load_reference(kind)
-    c = flops.StepCounts(ref, m)
+    c = flops.StepCounts(ref, m, run.load_family("image", FEDBENCH.parent).example_bytes(m))
     c.add(10, 50)
     p = flops.n_params(ref, m)
     assert c.examples == 500 and c.steps == 10

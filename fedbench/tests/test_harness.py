@@ -1,6 +1,7 @@
-"""The harness end to end on the CPU at a small size: a cell added from
-new files alone, the refusals, and the faults and the control that the
-check must catch."""
+"""The harness end to end on the CPU at a small size: a cell and a family
+added from new files alone, the refusals, and the faults and the control
+that the check must catch."""
+import dataclasses
 import json
 import shutil
 from pathlib import Path
@@ -15,6 +16,9 @@ import run
 
 FEDBENCH = Path(__file__).resolve().parents[1]
 ROOT = FEDBENCH.parent
+#: the sources of a family for the tests only (sentence classification
+#: with the program's LSTM, AdamW, one client at a time)
+SEQCLS = Path(__file__).resolve().parent / "data" / "seqcls"
 #: the real cells, each with a small mix of the same shape
 CELLS = {"femnist_cnn.uniform_b50": [[8, 1.0]],
          "fedavg_2nn.ragged_b10_50": [[4, 0.5], [8, 0.5]]}
@@ -87,6 +91,39 @@ def test_new_cell_from_new_files(checkout):
     assert res["correct"] is True and res["failed"] == 0
 
 
+def add_seqcls(root, add_cell):
+    """The test-only family, its reference, configuration and cell, added
+    to the checkout by new files and new entries of ``BENCHMARK.json``."""
+    fb = root / "fedbench"
+    shutil.copy(SEQCLS / "family.py", fb / "families" / "seqcls.py")
+    shutil.copy(SEQCLS / "reference.py", fb / "reference" / "lstm.py")
+    shutil.copy(SEQCLS / "config.json", fb / "configs" / "lstm_tiny.json")
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    bench["configs"].append({"name": "lstm_tiny", "source": "https://aclanthology.org/D13-1170",
+                             "file": "fedbench/configs/lstm_tiny.json", "reduced": [],
+                             "why": "the program's LSTM on SST-2-shaped sentences"})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    return add_cell("lstm_tiny.adamw_b8", "lstm_tiny",
+                    json.loads((SEQCLS / "traffic.json").read_text()),
+                    json.loads((SEQCLS / "limits.json").read_text()))
+
+
+def test_new_family_from_new_files(checkout, monkeypatch):
+    """A family whose data, model, reference and local rule the image
+    family does not have (token sequences, the LSTM, AdamW, clients one
+    at a time) runs, checks correct, and catches a planted fault, with no
+    file of the benchmark edited."""
+    root, add_cell, unchanged = checkout
+    name = add_seqcls(root, add_cell)
+    res = run_cell(root, name)
+    assert unchanged()
+    assert res["correct"] is True, res["checks"]
+    assert res["failed"] == 0 and res["run"]["warmed_compositions"] == 0
+    _plant(monkeypatch, "half_batch")
+    res = run_cell(root, name, seed=135792468013)
+    assert res["correct"] is False, res["checks"]
+
+
 def test_refuses_off_tpu(capsys):
     lines = []
     rc = run.main(["--workload", "fedavg_2nn.ragged_b10_50", "--seed", "1", "--seconds", "1"],
@@ -156,8 +193,7 @@ class _HalfData:
 
     def batches(self, n):
         for b in self._data.batches(n):
-            k = len(b["y"]) // 2
-            yield {"x": b["x"][:k], "y": b["y"][:k]}
+            yield {k: v[: len(v) // 2] for k, v in b.items()}
 
 
 class _HalfClient:
@@ -167,8 +203,14 @@ class _HalfClient:
 
 def _plant(monkeypatch, fault):
     from repro.fed.batch_exec import BatchedExecutor
+    from repro.fed.client import FLClient
 
     orig = BatchedExecutor.run_wave
+    if fault == "half_batch":
+        # clients trained one at a time lose the same half
+        train_local = FLClient.train_local
+        monkeypatch.setattr(FLClient, "train_local", lambda self, *a, **kw: train_local(
+            dataclasses.replace(self, data=_HalfData(self.data)), *a, **kw))
 
     def broken(self, gp, clients, n_steps, round_idx=0):
         if fault == "half_batch":

@@ -1,7 +1,8 @@
 """The plain references against the program, on the CPU at small sizes:
-the forward pass, one local training and a whole replayed round."""
+the forward pass, one local step, and the replay's AdamW."""
 import json
 from pathlib import Path
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
@@ -9,9 +10,10 @@ import numpy as np
 import pytest
 
 import replay
-from repro.models.small import SmallModelConfig, small_apply
+from repro.data.pipeline import ClientDataset
+from repro.fed.client import FLClient, build_step_fn
+from repro.models.small import SmallModelConfig, small_apply, small_loss
 from repro.optim.optimizers import make_optimizer
-from repro.fed.client import build_step_fn
 
 CONFIGS = Path(__file__).resolve().parents[1] / "configs"
 
@@ -49,6 +51,34 @@ def test_forward_and_step_match_program(name, kind):
         mine1 = jax.tree.map(lambda a, b: a - 0.05 * scale * b, params, g)
     for a, b in zip(jax.tree.leaves(mine1), jax.tree.leaves(p1["main"])):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+
+
+def test_replay_adamw_matches_program_two_steps():
+    """The replay's plain AdamW against two steps of the program's
+    ``make_optimizer("adamw", lr)`` (the trainer's: no weight decay) on one
+    client, both on the program's loss, so that only the local rule
+    differs."""
+    m = small("fedavg_2nn", hidden=32)
+    cfg = SmallModelConfig(**m)
+    # the program's loss in a reference's clothes: the same gradient on both sides
+    ref = SimpleNamespace(loss=lambda p, batch, m, precision: small_loss({"main": p}, cfg, batch)[0])
+    params = replay.load_reference("mlp").init(jax.random.key(5), m)
+    x = np.asarray(jax.random.normal(jax.random.key(6), (24, 28, 28, 1)))
+    data = ClientDataset(x, np.arange(24, dtype=np.int32) % 10, 8, seed=7)
+    lr = 0.01
+    with jax.default_matmul_precision("highest"):
+        client = FLClient(0, 100.0, replay.copy.deepcopy(data))
+        opt = make_optimizer("adamw", lr)
+        delta, _, _ = client.train_local({"main": params}, jax.jit(build_step_fn(cfg, opt)),
+                                         opt, n_steps=2)
+        out = replay.follow(ref, m, params, [replay.CheckedRound([0], [data])], 2, lr,
+                            {"x": x[:4], "y": np.zeros(4, np.int32)}, optimizer="adamw",
+                            delta_rounds=(0,))
+    mine = out.client_deltas[0][0]
+    # Adam moves an entry by about lr a step
+    assert max(float(np.max(np.abs(a))) for a in jax.tree.leaves(mine)) > 1.5 * lr
+    for a, b in zip(jax.tree.leaves(mine), jax.tree.leaves(delta["main"])):
+        np.testing.assert_allclose(a, b, rtol=0, atol=1e-6)
 
 
 def test_worst_leaf_gap_rules():

@@ -1,7 +1,7 @@
 """The program's ``fedhc.*`` spans read from a profiler trace: on
 hand-made planes with nested spans and args, on the small trace recorded
 on a TPU v5e (``data/small.xplane.pb``, from a program without such
-spans), and through ``span_run.py``'s result line."""
+spans), and through the result line of a traced ``run.py``."""
 import dataclasses
 import json
 from pathlib import Path
@@ -10,13 +10,17 @@ from types import SimpleNamespace as NS
 import pytest
 
 import replay
+import run
 import span_reduce as sr
-import span_run
 import trace_reduce as tr
 from test_harness import checkout, small_mix  # noqa: F401 (a fixture)
 
 FEDBENCH = Path(__file__).resolve().parents[1]
 DATA = Path(__file__).resolve().parent / "data" / "small.xplane.pb"
+#: the per-layer metrics of ``BENCHMARK.json`` read from the program's spans
+SPAN_METRICS = sorted(
+    m["name"] for m in json.loads((FEDBENCH.parent / "BENCHMARK.json").read_text())["per_layer"]
+    if m["source"] == "program_span")
 
 
 def ev(name, start_ms, dur_ms, **stats):
@@ -88,7 +92,7 @@ def test_innermost_flattens_nested_spans():
     assert sr.innermost([]) == []
 
 
-@pytest.mark.parametrize("name", sorted(span_run.SPAN_METRICS))
+@pytest.mark.parametrize("name", SPAN_METRICS)
 def test_span_metrics_read(name):
     """Each span metric reads its span per round, and nothing from a
     trace without the program's spans or from no trace."""
@@ -109,23 +113,67 @@ def test_recorded_v5e_trace_reads_as_trace_reduce():
     assert s.idle_by_span == pytest.approx({"host.other": s.window_s - s.busy_s})
 
 
-def test_span_run_adds_the_span_metrics(checkout, monkeypatch):
-    """``span_run`` prints run.py's line with the span metrics and the
-    idle split added (the trace replaced by the hand-made planes: a CPU
-    trace has no device plane)."""
+def test_traced_run_adds_the_span_metrics(checkout, monkeypatch):
+    """A traced ``run.py`` prints the span metrics of ``BENCHMARK.json``,
+    and the spans and the idle split under ``run`` (the trace replaced by
+    the hand-made planes: a CPU trace has no device plane)."""
     root, add_cell, _ = checkout
     name = add_cell("fedavg_2nn.ragged_b10_50", "fedavg_2nn",
                     small_mix("ragged_b10_50", [[4, 0.5], [8, 0.5]]),
                     {k: {"limit": 1e-3} for k in replay.NUMBERS})
+    # the span metrics list the cells they are read in: the copy's too
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    for m in bench["per_layer"]:
+        if m["name"] in SPAN_METRICS and "workloads" in m:
+            m["workloads"].append(name)
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
     monkeypatch.setattr(sr, "reduce_file", lambda path: sr.reduce_planes(hand_made()))
     lines = []
-    rc = span_run.main(["--workload", name, "--seed", "987654321012", "--seconds", "1",
-                        "--trace", "1"], root=root, require_tpu=False, emit=lines.append)
+    rc = run.main(["--workload", name, "--seed", "987654321012", "--seconds", "1",
+                   "--trace", "1"], root=root, require_tpu=False, emit=lines.append)
     assert rc == 0
     out = json.loads(lines[-1])
-    assert set(span_run.SPAN_METRICS) <= set(out["metrics"])
+    assert len(SPAN_METRICS) == 6 and set(SPAN_METRICS) <= set(out["metrics"])
     assert out["metrics"]["d2h_mb"]["unit"] == "MB"
     assert sum(out["run"]["idle_by_span"].values()) == pytest.approx(0.082)
     assert out["run"]["span_args"]["fedhc.wave.fetch"] == {"d2h_bytes": 5000}
-    # run.py's own reduction is back in place
-    assert tr.reduce_file is not None and tr.reduce_file.__module__ == "trace_reduce"
+    assert out["run"]["span_n"]["fedhc.wave.wait"] == 1
+    assert out["run"]["span_s"]["fedhc.fold.sum"] == pytest.approx(0.018)
+
+
+def with_a_device(path):
+    """A real CPU trace, read as ``span_reduce`` reads a chip's, with one
+    device plane added: the host's spans are the run's own."""
+    from jax.profiler import ProfileData
+
+    planes = [plane(p.name, **{line.name.replace(" ", "_"): [
+        NS(name=e.name, start_ns=e.start_ns, duration_ns=e.duration_ns,
+           stats=list(e.stats) if e.name.startswith(sr.PREFIX) else [])
+        for e in line.events] for line in p.lines}) for p in ProfileData.from_file(str(path)).planes]
+    lo = min(e.start_ns for p in planes for line in p.lines for e in line.events
+             if e.name == tr.WINDOW)
+    dev = plane("/device:TPU:0", XLA_Ops=[NS(name="add.1", start_ns=lo, duration_ns=1e6)],
+                XLA_Modules=[NS(name="jit_add(1)", start_ns=lo, duration_ns=1e6)])
+    return sr.reduce_planes(planes + [dev])
+
+
+def test_a_cell_without_waves_reads_the_fold_span(checkout, monkeypatch):
+    """The fold's span opens in every FedAvg round, so ``fold_host_ms``
+    lists no cells and a new cell reads it by its own files; a cell that
+    trains one client at a time has no wave spans and is given none of
+    the wave metrics.  The program's spans are those of a real traced run
+    on the CPU, with a device plane added (a CPU trace has none)."""
+    from test_harness import add_seqcls, run_cell
+
+    root, add_cell, unchanged = checkout
+    name = add_seqcls(root, add_cell)
+    listed = {m["name"] for m in run.load_cell(name, root)["per_layer"]}
+    assert listed & set(SPAN_METRICS) == {"fold_host_ms"}
+    monkeypatch.setattr(sr, "reduce_file", with_a_device)
+    out = run_cell(root, name, trace=1)
+    assert unchanged()
+    assert out["metrics"]["fold_host_ms"]["value"] > 0
+    assert not set(out["metrics"]) & (set(SPAN_METRICS) - {"fold_host_ms"})
+    assert out["run"]["span_n"]["fedhc.fold.sum"] == out["run"]["rounds"]
+    assert out["run"]["span_n"]["fedhc.client.train"] > 0
+    assert not any(k.startswith("fedhc.wave.") for k in out["run"]["span_n"])
